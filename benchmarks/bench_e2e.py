@@ -90,11 +90,11 @@ def run(n=None, scaling=True):
                 base = res
             emit(
                 f"table2_conc_{card}_{dist}_k{k}", res["us_conc"],
-                f"speedup_vs1={base['us_conc']/res['us_conc']:.2f};vs_part={res['us_part']/res['us_conc']:.2f}",
+                f"speedup_vs1={base['us_conc']/res['us_conc']:.2f};vs_part={res['us_part']/res['us_conc']:.2f};platform={res['platform']}",
             )
             emit(
                 f"table2_part_{card}_{dist}_k{k}", res["us_part"],
-                f"speedup_vs1={base['us_part']/res['us_part']:.2f}",
+                f"speedup_vs1={base['us_part']/res['us_part']:.2f};platform={res['platform']}",
             )
 
 

@@ -196,7 +196,8 @@ def run(n: int | None = None, json_path: str | None = None):
     results["remesh"] = mesh
     emit("elastic_remesh", mesh["remesh_us"],
          f"re-bucket 4→3 devices at chunk {CHUNKS // 2}/{CHUNKS}, "
-         f"exact={'yes' if mesh['remesh_exact'] else 'NO'}")
+         f"exact={'yes' if mesh['remesh_exact'] else 'NO'} "
+         f"platform={mesh['platform']}")
     emit("elastic_recovery", mesh["recovery_us"],
          f"re-mesh + finish vs {mesh['replay_us']:.0f}us full replay "
          f"(ratio {mesh['ratio']:.2f})")

@@ -244,7 +244,7 @@ def run(n: int | None = None, json_path: str | None = None,
             "stream_sharded", res["us"],
             f"rss={res['peak_rss_mb']:.0f}MB "
             f"buffered_chunks={res['peak_buffered_chunks']} "
-            f"groups={res['groups']}",
+            f"groups={res['groups']} platform={res['platform']}",
         )
 
     if json_path:

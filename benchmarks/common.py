@@ -66,9 +66,13 @@ def time_fn(fn, *args, warmup: int = 2, runs: int = 5) -> float:
 
 
 def run_in_devices(k: int, code: str, env_extra=None) -> dict:
-    """Run python code in a subprocess with k simulated devices; the code
-    must print a single json line on stdout."""
+    """Run python code in a subprocess with k SIMULATED CPU devices; the
+    code must print a single json line on stdout.  A CPU simulation by
+    design: the child is held to the CPU (a parent that imported JAX may
+    hold the accelerator, and a child reaching for it would fail or hang),
+    and its result says so under ``"platform"``."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={k}"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     env.update(env_extra or {})
@@ -78,7 +82,9 @@ def run_in_devices(k: int, code: str, env_extra=None) -> dict:
     )
     if out.returncode != 0:
         raise RuntimeError(out.stderr[-2000:])
-    return json.loads(out.stdout.strip().splitlines()[-1])
+    res = json.loads(out.stdout.strip().splitlines()[-1])
+    res["platform"] = "cpu"
+    return res
 
 
 def emit(name: str, us: float, derived: str = ""):

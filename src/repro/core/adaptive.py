@@ -44,10 +44,31 @@ class Plan:
     kernel: str | None = None  # fused | None (planner's ExecutionPolicy.kernel pick)
 
 
-#: VMEM per TensorCore on the TPU generations we target (bytes).  The fused
-#: kernel must co-house its table, ticket map and accumulators with the
-#: morsel blocks and compiler scratch, so the planner only claims a quarter.
-VMEM_BYTES = 16 * 1024 * 1024
+#: VMEM per TensorCore (bytes), keyed by ``jax.Device.device_kind``.  Source:
+#: ``TpuInfo.vmem_capacity_bytes`` in jax 0.9.0
+#: (``jax/_src/pallas/mosaic/tpu_info.py``).  The fused kernel must co-house
+#: its table, ticket map and accumulators with the morsel blocks and
+#: compiler scratch, so the planner only claims a quarter.
+VMEM_BYTES = {
+    "TPU v4": 16 * 1024 * 1024,
+    "TPU v5 lite": 128 * 1024 * 1024,
+    "TPU v5e": 128 * 1024 * 1024,
+    "TPU v5": 64 * 1024 * 1024,
+    "TPU v5p": 64 * 1024 * 1024,
+    "TPU v6 lite": 128 * 1024 * 1024,
+}
+
+
+def vmem_bytes(device_kind: str) -> int:
+    """VMEM per core of a TPU ``device_kind``; an unknown kind is an error,
+    never a guess."""
+    try:
+        return VMEM_BYTES[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no VMEM size recorded for TPU device_kind {device_kind!r}; "
+            f"known kinds: {sorted(VMEM_BYTES)}"
+        ) from None
 
 
 def fused_table_bytes(est_groups: int, num_accumulators: int = 1,
@@ -62,25 +83,31 @@ def fused_table_bytes(est_groups: int, num_accumulators: int = 1,
 
 
 def kernel_table_budget() -> int:
-    """VMEM bytes the planner lets a fused table claim: a quarter of VMEM on
-    TPU, 0 elsewhere — in interpret mode the fused route is correct but has
-    no residency advantage, so off-TPU plans keep the scan pipeline unless
-    the caller sets ``ExecutionPolicy.kernel`` (or a ``vmem_budget``)
-    explicitly."""
-    return VMEM_BYTES // 4 if jax.default_backend() == "tpu" else 0
+    """VMEM bytes the planner lets a fused table claim: a quarter of the
+    device's VMEM on TPU, 0 elsewhere — in interpret mode the fused route is
+    correct but has no residency advantage, so off-TPU plans keep the scan
+    pipeline unless the caller sets ``ExecutionPolicy.kernel`` (or a
+    ``vmem_budget``) explicitly."""
+    if jax.default_backend() != "tpu":
+        return 0
+    return vmem_bytes(jax.devices()[0].device_kind) // 4
 
 
 def choose_plan(stats: WorkloadStats, *, num_accumulators: int = 1,
                 vmem_budget: int | None = None) -> Plan:
+    from repro.kernels.ops import backend_refusal
+
     unique_frac = stats.est_groups / max(stats.n_rows, 1)
     heavy = stats.est_top_freq >= 0.25
     cap = table_capacity(stats.est_groups)
     budget = kernel_table_budget() if vmem_budget is None else vmem_budget
     # bound the fused fit check at the 2× headroom the resolver actually
-    # binds, so a fused pick doesn't immediately outgrow VMEM
+    # binds, so a fused pick doesn't immediately outgrow VMEM; never pick a
+    # kernel this backend's compiler refuses
     fused = (
         "fused"
         if fused_table_bytes(2 * stats.est_groups, num_accumulators) <= budget
+        and backend_refusal("fused") is None
         else None
     )
 

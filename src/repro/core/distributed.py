@@ -38,6 +38,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core import resize
@@ -47,7 +49,6 @@ from repro.core.aggregation import GroupByResult
 from repro.core.hashing import (EMPTY_KEY, partition_hash, slot_hash,
                                 table_capacity)
 from repro.core.partitioned import make_preagg, preagg_morsel
-from repro.parallel.sharding import shard_map
 
 
 # ---------------------------------------------------------------------------
@@ -249,13 +250,31 @@ def make_sharded_consume_step(mesh, axis: str, *, update: str,
     return step
 
 
+def replicated(tree):
+    """Re-place every mesh-sharded leaf of ``tree`` replicated on its own
+    mesh.  Gathers over an array whose type carries a sharded axis (an
+    ``Explicit``-axis mesh, the ``jax.make_mesh`` default) need an
+    ``out_sharding``; host-side bookkeeping over O(devices × max_local)
+    carried state runs on replicated copies instead."""
+
+    def one(x):
+        sh = getattr(x, "sharding", None)
+        if isinstance(sh, NamedSharding) and sh.spec != P():
+            return jax.device_put(x, sh.update(spec=P()))
+        return x
+
+    return jax.tree_util.tree_map(one, tree)
+
+
 def grow_sharded_carry(carry: ShardedCarry, new_max_local: int,
                        new_capacity: int) -> ShardedCarry:
     """Mesh analogue of the operator's pause-time growth: widen every
     device's bound (pad ``kbt`` + every accumulator with its kind's neutral
     — tickets are stable) and/or migrate every device's probe table (vmapped
-    contention-less §4.4 migration).  Uniform across devices so shapes stay
-    static."""
+    contention-less §4.4 migration, over a replicated copy of the carry;
+    the next consume step re-shards it).  Uniform across devices so shapes
+    stay static."""
+    carry = replicated(carry)
     kbt, acc = carry.kbt, carry.acc
     if new_max_local > carry.max_local:
         ndev, pad = kbt.shape[0], new_max_local - carry.max_local
@@ -408,8 +427,24 @@ def sharded_psum_merge(mesh, axis: str, carry: ShardedCarry, *,
     sticky per-device loss flags (psum'd) and the union-table overflow, for
     the saturation policy to inspect.
     """
-    cap_global = table_capacity(max_groups)
-    max_local = carry.max_local
+    gstate, key_by_ticket, count, lovf, govf = _psum_merge(
+        carry.kbt, carry.acc, carry.ovf, mesh=mesh, axis=axis,
+        max_groups=max_groups,
+    )
+    return key_by_ticket, gstate, count, lovf, govf
+
+
+@functools.partial(jax.jit, static_argnames=("mesh", "axis", "max_groups"))
+def _psum_merge(kbt, acc, ovf, *, mesh, axis: str, max_groups: int):
+    # one compiled program per (mesh, bound): op-by-op dispatch of the
+    # shard_map body would compile every primitive on its own
+    max_local = kbt.shape[1]
+    # The union's probe table holds every gathered key, not just the bound:
+    # a bound below the union then still counts the union exactly (tickets
+    # past the bound only set the overflow flag), so GROW re-merges once at
+    # the true size.  Sized to the bound alone, a full table keeps every
+    # unplaced lane probing for 2·capacity rounds per merge.
+    cap_global = table_capacity(max(max_groups, mesh.shape[axis] * max_local))
 
     def local(kbt, lacc, ovf):
         local_keys = kbt[0]
@@ -440,14 +475,12 @@ def sharded_psum_merge(mesh, axis: str, carry: ShardedCarry, *,
         govf = gtable.overflowed.astype(jnp.int32)
         return gstate, gtable.key_by_ticket, gtable.count, lovf, govf
 
-    fn = shard_map(
+    return shard_map(
         local, mesh=mesh,
         in_specs=(P(axis, None), P(axis, None), P(axis)),
         out_specs=(P(), P(), P(), P(), P()),
         check_vma=False,
-    )
-    gstate, key_by_ticket, count, lovf, govf = fn(carry.kbt, carry.acc, carry.ovf)
-    return key_by_ticket, gstate, count, lovf, govf
+    )(kbt, acc, ovf)
 
 
 def sharded_exchange_merge(mesh, axis: str, carry: ShardedCarry, *,
@@ -467,11 +500,18 @@ def sharded_exchange_merge(mesh, axis: str, carry: ShardedCarry, *,
     (static-shape exchange); callers grow ``partition_capacity`` and re-run
     — cheap, since the input is carried state, not rows.
     """
+    cap = partition_capacity or max(2 * carry.max_local // mesh.shape[axis], 16)
+    return _exchange_merge(
+        carry.kbt, carry.acc, carry.ovf, mesh=mesh, axis=axis,
+        max_groups=max_groups, cap=cap,
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("mesh", "axis", "max_groups", "cap"))
+def _exchange_merge(kbt, acc, ovf, *, mesh, axis: str, max_groups: int, cap: int):
     ndev = mesh.shape[axis]
-    max_local = carry.max_local
-    specs = carry.acc.specs
+    specs = acc.specs
     merge_kinds = tuple(_MERGE_KIND[k] for _, k in specs)
-    cap = partition_capacity or max(2 * max_local // ndev, 16)
 
     def local(kbt, lacc, ovf):
         allk = kbt[0]
@@ -511,14 +551,12 @@ def sharded_exchange_merge(mesh, axis: str, carry: ShardedCarry, *,
             lovf,
         )
 
-    fn = shard_map(
+    return shard_map(
         local, mesh=mesh,
         in_specs=(P(axis, None), P(axis, None), P(axis)),
         out_specs=(P(axis), P(axis), P(axis), P(axis), P()),
         check_vma=False,
-    )
-    keys_p, vals_p, counts_p, overflow_p, lovf = fn(carry.kbt, carry.acc, carry.ovf)
-    return keys_p, vals_p, counts_p, overflow_p, lovf
+    )(kbt, acc, ovf)
 
 
 def concurrent_groupby_sharded(

@@ -133,6 +133,7 @@ def make_executor(plan: GroupByPlan):
     and keeps running statistics across the stream for mid-stream
     re-planning."""
     plan = normalize_kernel(plan)
+    _refuse_uncompilable_kernel(plan)
     kernel = plan.execution.kernel
     if kernel in ("split", "fused"):
         if plan.strategy not in ("auto", "concurrent"):
@@ -195,6 +196,24 @@ def make_executor(plan: GroupByPlan):
 # shared helpers
 
 
+def _refuse_uncompilable_kernel(plan: GroupByPlan) -> None:
+    """An explicit kernel route that this backend's compiler refuses raises
+    here, quoting the compiler — never a quiet switch to another route."""
+    from repro.kernels.ops import backend_refusal
+
+    kernel, update = plan.execution.kernel, plan.execution.update
+    if kernel == "scan_body" and update is None and (
+        plan.strategy == "auto" or plan.max_groups is None
+    ):
+        return  # the planner picks the update; the resolved plan is checked
+    refusal = backend_refusal(kernel, update or "scatter")
+    if refusal is not None:
+        raise NotImplementedError(
+            f"kernel={kernel!r} (update={update!r}) does not compile for "
+            f"{jax.default_backend()}: the compiler raises {refusal!r}"
+        )
+
+
 def _instrument(plan: GroupByPlan) -> bool:
     """Resolve the per-plan instrumentation flag: an explicit
     ``ExecutionPolicy.instrument`` wins; ``None`` follows the global
@@ -212,6 +231,11 @@ class _ExecutorBase:
     peak_buffered_chunks = 0  # chunks retained beyond the in-flight window
     peak_retained_bytes = 0   # host bytes retained beyond the in-flight window
     strategy_label = "?"      # labeled-series key for registry publishing
+
+    @property
+    def plan(self) -> GroupByPlan:
+        """The plan this executor runs (the resolved one, for ``auto``)."""
+        return self._plan
 
     def open(self) -> None:
         pass
@@ -433,6 +457,10 @@ class _ResolvingExecutor(_ExecutorBase):
     @property
     def strategy_label(self) -> str:
         return self._inner.strategy_label if self._inner else "auto"
+
+    @property
+    def plan(self) -> GroupByPlan:
+        return self._inner.plan if self._inner else self._plan
 
     def device_table_bytes(self) -> int:
         return self._inner.device_table_bytes() if self._inner else 0
@@ -1865,8 +1893,11 @@ class _ShardedExecutor(_ExecutorBase):
         else:
             # Unify the per-partition outputs: stable compaction of each
             # owner's valid prefix (partitions are disjoint, so the keys
-            # are globally unique).  Pure jnp — no host round-trip.
-            keys_p, vals_p, counts_p = self._merged
+            # are globally unique).  Pure jnp over a replicated copy — no
+            # host round-trip.
+            from repro.core.distributed import replicated
+
+            keys_p, vals_p, counts_p = replicated(self._merged)
             ndev = self._ndev
             per_dev = keys_p.shape[0] // ndev
             idx = jnp.arange(keys_p.shape[0])
@@ -1888,6 +1919,13 @@ class _ShardedExecutor(_ExecutorBase):
         return sum(
             int(leaf.nbytes) for leaf in jax.tree_util.tree_leaves(self._carry)
         )
+
+    def local_group_counts(self) -> list[int]:
+        """Groups each device holds before the merge, in mesh order (one
+        host read)."""
+        if self._carry is None:
+            return [0] * self._ndev
+        return [int(c) for c in np.asarray(jax.device_get(self._carry.count))]
 
     def event_counts(self):
         if not self._collect or self._events is None:

@@ -449,8 +449,9 @@ def fused_consume(
     control vector — the host reads it ONCE per chunk (the same sync
     cadence as the scan pipeline's halt flags) to drive pause → grow →
     resume."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    from repro.kernels.ops import _auto_interpret
+
+    interpret = _auto_interpret(interpret)
     P, C = state.tkeys.shape
     S, _, G = state.accs.shape
     V, total, M = values.shape
@@ -520,7 +521,7 @@ def fused_groupby_pallas(
     max_groups: int,
     kind: str = "sum",
     morsel_size: int = 1024,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """One fused pass: keys+values morsels → (key_by_ticket, acc, count).
 

@@ -27,12 +27,44 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.hashing import EMPTY_KEY, slot_hash
-from repro.kernels.segment_agg import segment_agg_pallas
+from repro.kernels.segment_agg import LANES, segment_agg_pallas
 from repro.kernels.ticket_hash import ticket_hash_pallas
 
 
-def _auto_interpret() -> bool:
+def _auto_interpret(interpret: bool | None = None) -> bool:
+    """THE interpret-mode rule for every kernel entry point: an explicit
+    flag wins; otherwise compile for the chip on a TPU backend and run the
+    Pallas interpreter everywhere else."""
+    if interpret is not None:
+        return interpret
     return jax.default_backend() != "tpu"
+
+
+#: Kernel routes the TPU compiler (Mosaic, jax 0.9.0, v5e) refuses, with its
+#: own words.  The probe gathers and the claim/accumulate scatters over the
+#: VMEM table are vector gathers/scatters, which Mosaic does not lower; these
+#: routes run in interpret mode only.  ``scan_body`` compiles with the
+#: ``onehot`` update and is refused with every other update (all of which
+#: map to the kernel's ``scatter`` strategy).
+TPU_REFUSALS = {
+    "fused": "NotImplementedError: Only 2D gather is supported",
+    "split": "NotImplementedError: Only 2D gather is supported",
+    "scan_body": (
+        "NotImplementedError: Unimplemented primitive in Pallas TPU lowering "
+        "for KernelType.TC: scatter-add"
+    ),
+}
+
+
+def backend_refusal(kernel: str | None, update: str | None = None) -> str | None:
+    """The compiler's refusal of a kernel route on the current backend, or
+    None when the route compiles there (every route runs off-TPU, in
+    interpret mode)."""
+    if jax.default_backend() != "tpu" or kernel not in TPU_REFUSALS:
+        return None
+    if kernel == "scan_body" and update == "onehot":
+        return None
+    return TPU_REFUSALS[kernel]
 
 
 # Deprecation shims: the engine selects kernels through the single
@@ -75,13 +107,11 @@ def _ticket(
     Contract: the returned ``count`` must be checked against ``max_groups``
     by the caller — tickets past the bound had their ``key_by_ticket``
     scatters dropped (truncated materialization)."""
-    if interpret is None:
-        interpret = _auto_interpret()
     n = keys.shape[0]
     kp = _pad_to(keys.astype(jnp.uint32), morsel_size, EMPTY_KEY)
     tickets, tkeys, ttks, kbt, count = ticket_hash_pallas(
         kp, capacity=capacity, max_groups=max_groups,
-        morsel_size=morsel_size, interpret=interpret,
+        morsel_size=morsel_size, interpret=_auto_interpret(interpret),
     )
     return tickets[:n], kbt, count
 
@@ -118,14 +148,15 @@ def _segment_aggregate(
     morsel_size: int = 1024,
     interpret: bool | None = None,
 ):
-    if interpret is None:
-        interpret = _auto_interpret()
+    # one morsel at most the (lane-rounded) input: the kernel's block is
+    # then 8x128-tileable or the whole array
     n = tickets.shape[0]
+    morsel_size = min(morsel_size, -(-max(n, 1) // LANES) * LANES)
     tp = _pad_to(tickets.astype(jnp.int32), morsel_size, -1)
     vp = _pad_to(values.astype(jnp.float32), morsel_size, 0.0)
     return segment_agg_pallas(
         tp, vp, num_groups=num_groups, kind=kind, strategy=strategy,
-        morsel_size=morsel_size, interpret=interpret,
+        morsel_size=morsel_size, interpret=_auto_interpret(interpret),
     )
 
 
@@ -176,8 +207,7 @@ def make_scan_update_fn(
     def update_fn(acc, tickets, values, kind: str = "sum"):
         part = _segment_aggregate(
             tickets, values, num_groups=acc.shape[0], kind=kind,
-            strategy=strategy, morsel_size=min(morsel_size, tickets.shape[0]),
-            interpret=interpret,
+            strategy=strategy, morsel_size=morsel_size, interpret=interpret,
         )
         if kind in ("sum", "count"):
             return acc + part.astype(acc.dtype)

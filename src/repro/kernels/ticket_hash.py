@@ -161,7 +161,7 @@ def ticket_hash_pallas(
     capacity: int,
     max_groups: int,
     morsel_size: int = 1024,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Run the ticketing kernel over a key column.
 
@@ -170,8 +170,8 @@ def ticket_hash_pallas(
         EMPTY_KEY).
       capacity: table slots (pow2, ≤ 2^17 to stay in VMEM).
       max_groups: bound on unique keys (key_by_ticket length).
-      interpret: run in Pallas interpret mode (CPU validation). On TPU pass
-        False.
+      interpret: run in Pallas interpret mode.  The TPU compiler refuses
+        this kernel (``kernels.ops.TPU_REFUSALS``), so only True runs.
 
     Returns (tickets (N,) int32 0-based, table_keys, table_tickets,
     key_by_ticket (uint32), count ()).

@@ -289,34 +289,10 @@ def _scan_scale(cfg: ModelConfig) -> float:
     return float(cfg.n_layers)
 
 
-def _peak_bytes(mem):
-    """``peak_memory_in_bytes`` is post-0.4.x; on the pinned toolchain
-    reconstruct the per-device peak from the component sizes."""
-    if mem is None:
-        return None
-    peak = getattr(mem, "peak_memory_in_bytes", None)
-    if not peak:
-        # donated inputs alias outputs, so they are not live twice
-        peak = (getattr(mem, "argument_size_in_bytes", 0)
-                + getattr(mem, "output_size_in_bytes", 0)
-                + getattr(mem, "temp_size_in_bytes", 0)
-                - getattr(mem, "alias_size_in_bytes", 0))
-    return peak or None
-
-
-def _cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` returns a dict on newer jax but a
-    single-element ``[dict]`` on the pinned 0.4.x toolchain — normalize."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
 def _measure(mesh, cfg, cell, variant=None):
     lowered = lower_cell(mesh, cfg, cell, variant)
     compiled = lowered.compile()
-    cost = _cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     coll = collective_bytes(compiled.as_text())
     return compiled, cost, coll
 
@@ -357,7 +333,7 @@ def run_cell(arch: str, shape: str, *, multi_pod: bool = False, verbose: bool = 
         t_lower = time.time() - t0
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
-        raw_cost = _cost_analysis(compiled)
+        raw_cost = compiled.cost_analysis()
         raw_coll = collective_bytes(compiled.as_text())
         if with_cost:
             cost, coll = extrapolated_cost(mesh, cfg, cell, variant)
@@ -386,10 +362,7 @@ def run_cell(arch: str, shape: str, *, multi_pod: bool = False, verbose: bool = 
             "argument_bytes": getattr(mem, "argument_size_in_bytes", None),
             "output_bytes": getattr(mem, "output_size_in_bytes", None),
             "temp_bytes": getattr(mem, "temp_size_in_bytes", None),
-            "peak_bytes": _peak_bytes(mem),
-            # False when reconstructed from component sizes (0.4.x jaxlib):
-            # the component sum is an upper bound, not a liveness-aware peak
-            "peak_exact": bool(getattr(mem, "peak_memory_in_bytes", 0)),
+            "peak_bytes": mem.peak_memory_in_bytes if mem else None,
         },
         "cost": {k: cost.get(k) for k in ("flops", "bytes accessed", "transcendentals")},
         "cost_raw_scanned": {

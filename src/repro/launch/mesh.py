@@ -14,15 +14,26 @@ from __future__ import annotations
 import jax
 
 
+def make_auto_mesh(shape, axes):
+    """A mesh whose axes are all ``Auto``: the model harness leaves sharding
+    propagation to the compiler (GSPMD) and states shardings only at its
+    boundaries.  ``jax.make_mesh`` defaults to ``Explicit`` axes, under
+    which every gather and contraction over a sharded dim must state its
+    output sharding."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over whatever devices exist (tests / CPU benchmarks)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_auto_mesh((data, model), ("data", "model"))
 
 
 # v5e hardware constants for the roofline (per chip)

@@ -40,7 +40,7 @@ from repro.models.layers import (
     softcap,
     ticketed_embed,
 )
-from repro.parallel.sharding import shard_map
+from jax import shard_map
 
 
 # ---------------------------------------------------------------------------

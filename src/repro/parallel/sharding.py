@@ -29,19 +29,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-# jax.shard_map is only public in newer jax; fall back to its experimental
-# home on the pinned 0.4.x toolchain, where the replication-check kwarg is
-# still called check_rep rather than check_vma.
-try:
-    shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map_experimental
-
-    def shard_map(f, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map_experimental(f, **kwargs)
-
 from repro.models.config import ModelConfig
 
 M = "model"

@@ -213,6 +213,11 @@ class QueryHandle:
     def chunks_consumed(self) -> int:
         return self._stream.chunks_consumed
 
+    @property
+    def executor(self):
+        """The live executor of this query's stream."""
+        return self._stream.executor
+
     def stats(self) -> dict:
         """This query's ingest + memory telemetry
         (:meth:`repro.engine.plan_api.StreamHandle.stats`): chunk/row

@@ -35,7 +35,7 @@ from repro.optim.clip import clip_by_global_norm
 from repro.optim.compression import compressed_psum
 from repro.optim.schedules import warmup_cosine
 from repro.parallel.sharding import batch_spec, dp_axes, param_shardings, param_specs
-from repro.parallel.sharding import shard_map
+from jax import shard_map
 
 
 @dataclass(frozen=True)

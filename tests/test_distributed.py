@@ -197,7 +197,7 @@ def run_ep(x, pg, pu, pd, prouter):
     out, aux = moe_lib.moe_mlp_ep(p_loc, cfg, x, axis="model", num_shards=4,
                                   capacity_per_expert=cap)
     return out, aux
-from repro.parallel.sharding import shard_map
+from jax import shard_map
 fn = shard_map(run_ep, mesh=mesh,
         in_specs=(P(), P("model"), P("model"), P("model"), P()),
         out_specs=(P(), P()), check_vma=False)
@@ -219,7 +219,8 @@ from repro.models import transformer as tf
 from repro.optim import adamw
 from repro.train.loop import TrainHParams, make_train_step
 from repro.parallel.sharding import param_specs
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+from repro.launch.mesh import make_auto_mesh
+mesh = make_auto_mesh((2, 2, 2), ("pod", "data", "model"))
 cfg = get_config("qwen3_0_6b", reduced=True)
 params = tf.init_params(jax.random.PRNGKey(0), cfg)
 opt = adamw.init(params)

@@ -462,3 +462,64 @@ def test_resolver_adopts_fused_under_budget(monkeypatch):
     assert executors_mod.resolve_plan_stats(
         pinned, stats
     ).execution.kernel == "off"
+
+
+# ---------------------------------------------------------------------------
+# TPU backend: no route reaches a kernel the chip's compiler refuses
+
+
+@pytest.fixture
+def tpu_backend(monkeypatch):
+    """Make the planner and executors see a v5e TPU backend (nothing here
+    compiles or runs a kernel)."""
+    import types
+
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices", lambda *a: [
+        types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    ])
+
+
+def test_tpu_planner_never_picks_fused(tpu_backend):
+    assert adaptive.kernel_table_budget() == (128 << 20) // 4
+    stats = adaptive.WorkloadStats(n_rows=1_000_000, est_groups=1000,
+                                   est_top_freq=0.0)
+    assert adaptive.choose_plan(stats).kernel is None
+    assert adaptive.choose_plan(stats, vmem_budget=1 << 30).kernel is None
+    plan = GroupByPlan(keys=("__key__",), aggs=(AggSpec("count"),),
+                       strategy="auto", raw_keys=True)
+    resolved = executors_mod.resolve_plan_stats(plan, stats)
+    assert resolved.execution.kernel is None
+    assert resolved.execution.update == "onehot"
+
+
+@pytest.mark.parametrize("kernel,update,refused", [
+    ("fused", None, "Only 2D gather is supported"),
+    ("split", None, "Only 2D gather is supported"),
+    ("scan_body", "scatter", "scatter-add"),
+    ("scan_body", None, "scatter-add"),
+    ("scan_body", "onehot", None),
+    ("off", None, None),
+])
+def test_tpu_explicit_refused_kernel_raises(tpu_backend, kernel, update,
+                                            refused):
+    plan = GroupByPlan(keys=("__key__",), aggs=(AggSpec("count"),),
+                       strategy="concurrent", max_groups=1024, raw_keys=True,
+                       execution=ExecutionPolicy(kernel=kernel, update=update))
+    if refused is None:
+        executors_mod.make_executor(plan)
+        return
+    with pytest.raises(NotImplementedError, match=refused):
+        executors_mod.make_executor(plan)
+    # auto plans pinned to a refused kernel raise at make_executor too
+    if kernel in ("fused", "split"):
+        with pytest.raises(NotImplementedError, match=refused):
+            executors_mod.make_executor(plan.with_(strategy="auto"))
+
+
+def test_vmem_budget_refuses_unknown_device_kind():
+    assert adaptive.vmem_bytes("TPU v5 lite") == 128 << 20
+    with pytest.raises(ValueError, match="no VMEM size recorded"):
+        adaptive.vmem_bytes("TPU v99")

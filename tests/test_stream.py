@@ -340,6 +340,27 @@ def test_sharded_stream_multi_aggregate_grow():
     )
 
 
+def test_sharded_psum_merge_counts_union_past_bound():
+    """Under a global bound below the union, the dense-psum merge still
+    counts the union exactly and flags the overflow, so GROW re-merges once
+    at the true size (a probe table sized to the bound alone saturates,
+    caps the count at its capacity and spins every unplaced lane)."""
+    import jax
+
+    from repro.core import distributed as dist
+
+    mesh = jax.make_mesh((1,), ("data",))
+    carry = dist.make_sharded_carry(1, 1024, ((None, "count"),))
+    carry = carry._replace(
+        kbt=carry.kbt.at[0, :700].set(jnp.arange(1, 701, dtype=jnp.uint32)),
+        count=jnp.full((1,), 700, jnp.int32),
+    )
+    _, _, count, lost, union_ovf = dist.sharded_psum_merge(
+        mesh, "data", carry, max_groups=64
+    )
+    assert (int(count), int(lost), int(union_ovf)) == (700, 0, 1)
+
+
 # ---------------------------------------------------------------------------
 # ChunkSource adapters
 
