@@ -201,4 +201,10 @@ def _partitioned_impl(
     tickets, key_by_ticket, count = tk.sort_ticketing(allk)
     acc = up.init_acc(max_groups, kind)
     acc = up.sort_segment_update(acc, tickets, allv, kind="min" if kind == "min" else "max" if kind == "max" else "sum")
-    return GroupByResult(key_by_ticket[:max_groups], up.finalize(kind, acc), count)
+    # ticket slots number the input's entries (pre-aggregated plus
+    # spilled); a bound above that (a stream chunk under a large bound)
+    # would leave fewer keys than accumulators: pad, so both have
+    # ``max_groups`` rows
+    kbt = key_by_ticket[:max_groups]
+    kbt = jnp.pad(kbt, (0, max_groups - kbt.shape[0]), constant_values=EMPTY_KEY)
+    return GroupByResult(kbt, up.finalize(kind, acc), count)

@@ -25,6 +25,7 @@ tickets.
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
@@ -79,6 +80,10 @@ def make_table(capacity: int, max_groups: int | None = None) -> TicketTable:
     )
 
 
+# get_or_insert and lookup are jitted: called eagerly (the executors' host
+# merges and routing probes), the probe loop would close over the concrete
+# keys, embed them as constants and compile anew on every call.
+@functools.partial(jax.jit, static_argnames=("seed", "count_probes"))
 def get_or_insert(table: TicketTable, keys: jnp.ndarray, *, seed: int = 0,
                   count_probes: bool = False):
     """Vectorized GET_OR_INSERT over a morsel of keys (paper Algorithm 1).
@@ -210,6 +215,7 @@ def get_or_insert(table: TicketTable, keys: jnp.ndarray, *, seed: int = 0,
     return tickets, new_table
 
 
+@functools.partial(jax.jit, static_argnames=("seed",))
 def lookup(table: TicketTable, keys: jnp.ndarray, *, seed: int = 0) -> jnp.ndarray:
     """Read-only probe (the contention-free fast path). Returns 0-based
     tickets, -1 for absent or sentinel keys."""

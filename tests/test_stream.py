@@ -171,6 +171,22 @@ def test_grow_under_streaming_recovers(strategy):
     )
 
 
+@pytest.mark.parametrize("tail", [0, 100])
+def test_partitioned_stream_bound_above_chunk(tail):
+    """A bound larger than a chunk plus its pre-aggregation entries (every
+    chunk here, and a short last one when ``tail``): the chunk pipeline's
+    keys and values still line up with the carried table."""
+    keys = RNG.permutation(N + tail).astype(np.uint32)
+    plan = GroupByPlan(
+        keys=("k",), aggs=(AggSpec("count"),), strategy="partitioned",
+        max_groups=4 * N, raw_keys=True,
+    )
+    out = plan.stream(chunk_tables(keys)).result()
+    assert table_map(out, "count(*)") == oracle_map(
+        keys, None, kind="count", max_groups=N + tail
+    )
+
+
 def test_grow_streaming_with_deep_prefetch_matches_sync():
     """Deferred polls (prefetch window > 0) must not change results even
     when pauses fire while several chunks are in flight."""
