@@ -477,13 +477,14 @@ class _ResolvingExecutor(_ExecutorBase):
         return keys
 
     def _observe(self, chunk: Table) -> None:
-        stats = self._stats.update(self._sample_keys(chunk))
-        if self._inner is None:
-            self._resolved = resolve_plan_stats(self._plan, stats)
-            self._inner = make_executor(self._resolved)
-            self._inner.open()
-        else:
-            self._maybe_replan(stats)
+        with obs_trace.span("plan_sample"):
+            stats = self._stats.update(self._sample_keys(chunk))
+            if self._inner is None:
+                self._resolved = resolve_plan_stats(self._plan, stats)
+                self._inner = make_executor(self._resolved)
+                self._inner.open()
+            else:
+                self._maybe_replan(stats)
 
     def _maybe_replan(self, stats: adaptive.WorkloadStats) -> None:
         """hash→hybrid escalation on long streams: the first-chunk sample
@@ -1685,12 +1686,13 @@ class _ShardedExecutor(_ExecutorBase):
     def _run_step(self, km, vm, start):
         """One sharded consume step, threading the per-device event planes
         when instrumented.  Returns the per-device halt flags."""
-        if self._collect:
-            self._carry, halts, self._events = self._step(
-                self._carry, km, vm, start, self._events
-            )
-        else:
-            self._carry, halts = self._step(self._carry, km, vm, start)
+        with obs_trace.span("dispatch"):
+            if self._collect:
+                self._carry, halts, self._events = self._step(
+                    self._carry, km, vm, start, self._events
+                )
+            else:
+                self._carry, halts = self._step(self._carry, km, vm, start)
         return halts
 
     def _morselize(self, keys, vals):

@@ -361,11 +361,13 @@ class GroupByOperator:
         """
         if self._overflowed and self.check_overflow:
             return None  # poisoned: skip the scan, finalize raises anyway
-        keys, cols = chunk_key_column(chunk, self.key_columns, self.raw_keys)
+        with obs_trace.span("combine_keys"):
+            keys, cols = chunk_key_column(chunk, self.key_columns, self.raw_keys)
         value_cols = sorted({c for c, _ in self._state.specs if c is not None})
-        km, vm, num = morselize_chunk(
-            keys, {c: cols[c] for c in value_cols}, self.morsel_rows
-        )
+        with obs_trace.span("morselize"):
+            km, vm, num = morselize_chunk(
+                keys, {c: cols[c] for c in value_cols}, self.morsel_rows
+            )
         if self.pipeline == "host":
             self._consume_host_loop(km, vm, num)
             return None
@@ -382,19 +384,20 @@ class GroupByOperator:
         """Dispatch one ``_consume_scan`` pass, threading the device event
         vector through the carry when instrumented.  Returns the per-morsel
         halt flags (constant-false unchecked)."""
-        if self.collect_events:
-            self._table, self._state, halts, self._events = _consume_scan(
-                self._table, self._state, km, vm, jnp.int32(start),
-                self._events, update_fn=self._update_fn,
-                load_factor=self.load_factor, checked=checked,
-                grow_bound=checked and self.grow_bound, collect_events=True,
-            )
-        else:
-            self._table, self._state, halts = _consume_scan(
-                self._table, self._state, km, vm, jnp.int32(start),
-                update_fn=self._update_fn, load_factor=self.load_factor,
-                checked=checked, grow_bound=checked and self.grow_bound,
-            )
+        with obs_trace.span("dispatch"):
+            if self.collect_events:
+                self._table, self._state, halts, self._events = _consume_scan(
+                    self._table, self._state, km, vm, jnp.int32(start),
+                    self._events, update_fn=self._update_fn,
+                    load_factor=self.load_factor, checked=checked,
+                    grow_bound=checked and self.grow_bound, collect_events=True,
+                )
+            else:
+                self._table, self._state, halts = _consume_scan(
+                    self._table, self._state, km, vm, jnp.int32(start),
+                    update_fn=self._update_fn, load_factor=self.load_factor,
+                    checked=checked, grow_bound=checked and self.grow_bound,
+                )
         return halts
 
     def poll(self, token) -> None:

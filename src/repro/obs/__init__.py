@@ -5,7 +5,7 @@ Everything is OFF by default (no-op fast paths); enable explicitly::
 
     from repro.obs import metrics, trace
     metrics.enable()   # counters / gauges / histograms + device event vector
-    trace.enable()     # spans → Perfetto-loadable Chrome trace JSON
+    trace.enable()     # spans → Chrome trace JSON + repro.* profiler annotations
 
 or per-plan via ``ExecutionPolicy(instrument=True)``.
 """

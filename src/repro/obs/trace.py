@@ -1,4 +1,5 @@
-"""Span tracing → Chrome trace-event JSON (loadable in Perfetto / chrome://tracing).
+"""Span tracing → Chrome trace-event JSON (loadable in Perfetto / chrome://tracing)
+and the JAX profiler's timeline.
 
 Usage::
 
@@ -9,9 +10,12 @@ Usage::
     trace.save("stream.trace.json")
 
 Spans become ``"ph": "X"`` *complete* events (ts/dur in microseconds, the
-format Perfetto's Chrome-trace importer expects); :func:`instant` emits
-``"ph": "i"`` markers.  Disabled (the default), :func:`span` returns a shared
-no-op context manager and records nothing — the hot path pays one ``if``.
+format Perfetto's Chrome-trace importer expects).  Each span also opens a
+``jax.profiler.TraceAnnotation`` named ``repro.<name>`` (its args become the
+event's stats), so under a running ``jax.profiler`` trace the program's
+spans sit on the profiler's clock next to the device ops they wait on.
+Disabled (the default), :func:`span` returns a shared no-op context manager,
+records nothing and creates no annotation — the hot path pays one ``if``.
 
 The buffer is process-wide and thread-safe; ``pid``/``tid`` are real so
 scheduler quanta from worker threads land on their own Perfetto tracks.
@@ -26,10 +30,16 @@ import time
 _enabled = False
 _lock = threading.Lock()
 _events: list = []
+_annotation = None  # jax.profiler.TraceAnnotation, bound by enable()
+
+PREFIX = "repro."  # of every span's profiler annotation
 
 
 def enable() -> None:
-    global _enabled
+    global _enabled, _annotation
+    from jax.profiler import TraceAnnotation
+
+    _annotation = TraceAnnotation
     _enabled = True
 
 
@@ -70,17 +80,20 @@ _NOOP_SPAN = _NoopSpan()
 
 
 class _Span:
-    __slots__ = ("name", "args", "t0")
+    __slots__ = ("name", "args", "t0", "annotation")
 
     def __init__(self, name, args):
         self.name, self.args = name, args
+        self.annotation = _annotation(PREFIX + name, **args)
 
     def __enter__(self):
+        self.annotation.__enter__()
         self.t0 = _now_us()
         return self
 
     def __exit__(self, *exc):
         end = _now_us()
+        self.annotation.__exit__(*exc)
         ev = {
             "name": self.name,
             "ph": "X",
@@ -97,28 +110,11 @@ class _Span:
 
 
 def span(name: str, **args):
-    """Context manager timing one span. No-op (shared singleton) when disabled."""
+    """Context manager timing one span and annotating it on the profiler's
+    timeline as ``repro.<name>``.  No-op (shared singleton) when disabled."""
     if not _enabled:
         return _NOOP_SPAN
     return _Span(name, args)
-
-
-def instant(name: str, **args) -> None:
-    """A zero-duration marker event."""
-    if not _enabled:
-        return
-    ev = {
-        "name": name,
-        "ph": "i",
-        "ts": _now_us(),
-        "s": "t",
-        "pid": os.getpid(),
-        "tid": threading.get_ident(),
-    }
-    if args:
-        ev["args"] = args
-    with _lock:
-        _events.append(ev)
 
 
 def to_json() -> dict:

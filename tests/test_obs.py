@@ -7,11 +7,14 @@
   * spill accounting parity: the registry series the SpillExecutor
     publishes equal the SpillManager's own counters, and the residency
     invariant (hot table never migrates) is visible in the counters;
-  * span tracing emits valid Chrome-trace JSON with correctly nested spans;
+  * span tracing emits valid Chrome-trace JSON with correctly nested spans,
+    and puts each span on the JAX profiler's timeline as a ``repro.*``
+    annotation (a chunk's stages inside its ``consume_async``);
   * ``QueryHandle.profile()`` under a 2-tenant DRR run reports queue wait,
     quanta, ingest progress and device bytes per tenant;
   * disabled mode (the default) emits nothing — empty registry, empty
-    trace — while the unified ``stats()`` schema keeps every legacy key.
+    trace, no profiler annotation — while the unified ``stats()`` schema
+    keeps every legacy key.
 """
 import json
 
@@ -215,6 +218,74 @@ def test_trace_valid_chrome_json_with_nested_spans():
                 and e["ts"] + e.get("dur", 0) <= t["ts"] + t["dur"]
                 for t in tops
             ), e["name"]
+
+
+def _profiled(tmp_path, run) -> list:
+    """Run ``run()`` under the JAX profiler; returns the host timeline, one
+    list of ``(name, start_ns, end_ns)`` per host thread."""
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        run()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    data = ProfileData.from_file(str(path))
+    return [[(e.name, e.start_ns, e.start_ns + e.duration_ns) for e in line.events]
+            for plane in data.planes if plane.name.startswith("/host:")
+            for line in plane.lines]
+
+
+def _auto_stream():
+    keys = np.random.default_rng(4).integers(0, 300, N).astype(np.uint32)
+    plan = GroupByPlan(keys=("k",), aggs=(AggSpec("count"),), raw_keys=True,
+                       execution=ExecutionPolicy(morsel_rows=256))
+    return plan.stream(chunk_tables(keys)).result()
+
+
+def test_spans_on_profiler_timeline(tmp_path):
+    """Enabled spans land on the profiler's host timeline as ``repro.*``
+    annotations: the per-chunk stages nest in ``consume_async`` on its
+    thread (a grow replay's ``dispatch`` in ``pause_migrate_resume``), and
+    the Chrome-trace events are still recorded."""
+    obs_trace.enable()
+    host = _profiled(tmp_path, _auto_stream)
+    stages = ("plan_sample", "combine_keys", "morselize", "dispatch")
+    nested = {name: 0 for name in stages}
+    for line in host:
+        parents = {n: [(s, e) for name, s, e in line if name == "repro." + n]
+                   for n in ("consume_async", "pause_migrate_resume")}
+        for name, s, e in line:
+            stage = name.removeprefix("repro.")
+            if not name.startswith("repro.") or stage not in stages:
+                continue
+            inside = [p for p, ivs in parents.items()
+                      if any(ps <= s and e <= pe for ps, pe in ivs)]
+            assert inside == ["consume_async"] or (
+                stage == "dispatch" and inside == ["pause_migrate_resume"]
+            ), (name, inside)
+            nested[stage] += "consume_async" in inside
+    chunks = N // CHUNK
+    assert nested == {"plan_sample": chunks, "combine_keys": chunks,
+                      "morselize": chunks, "dispatch": chunks}
+    recorded = {e["name"] for e in obs_trace.events()}
+    assert {"consume_async", *stages} <= recorded
+
+
+def test_disabled_spans_leave_no_annotation(tmp_path, monkeypatch):
+    """Disabled, ``span`` returns the shared no-op without building an
+    annotation, and the profiler's timeline holds no ``repro.*`` event."""
+    def no_annotation(*a, **k):
+        raise AssertionError("a disabled span built an annotation")
+
+    monkeypatch.setattr(obs_trace, "_annotation", no_annotation)
+    assert obs_trace.span("dispatch") is obs_trace.span("plan_sample", k=1)
+    host = _profiled(tmp_path, _auto_stream)
+    assert not [name for line in host for name, _, _ in line
+                if name.startswith("repro.")]
+    assert obs_trace.events() == []
 
 
 # ---------------------------------------------------------------------------
