@@ -135,7 +135,8 @@ class RunningStats:
     hitter mass of a Zipf source only emerges over many chunks, and the
     distinct count grows without bound on near-unique streams).  This
     keeps a tiny host-side sketch updated from a prefix sample of every
-    chunk:
+    chunk (``update`` reads the sample itself; ``fold`` takes a host copy
+    read by the caller):
 
       * a Misra–Gries counter set (``num_counters`` slots) for heavy-hitter
         mass — deletions decrement all counters, so a surviving counter's
@@ -143,7 +144,7 @@ class RunningStats:
       * a bounded union of sampled distinct keys for the cardinality
         estimate (same u-anchored birthday estimator as ``sample_stats``).
 
-    ``strategy="auto"`` executors feed every chunk through ``update`` and
+    ``strategy="auto"`` executors feed every chunk through ``fold`` and
     re-plan when the observed stats cross a planner threshold (the
     hash→hybrid escalation), and the observed distinct count feeds back
     into capacity bounds.
@@ -163,13 +164,23 @@ class RunningStats:
 
     def update(self, keys: jnp.ndarray) -> "WorkloadStats":
         """Fold one chunk's prefix sample into the sketch; returns the
-        refreshed cumulative :class:`WorkloadStats`."""
+        refreshed cumulative :class:`WorkloadStats`.  Slices the sample off
+        ``keys`` and reads it to the host (blocking) for :meth:`fold`."""
         import numpy as np
 
         flat = keys.reshape(-1)
-        self.n_rows += int(flat.shape[0])
         s = min(self.sample, flat.shape[0])
-        ks = np.asarray(jax.device_get(flat[:s]))
+        return self.fold(np.asarray(jax.device_get(flat[:s])), int(flat.shape[0]))
+
+    def fold(self, host_keys, n_rows: int) -> "WorkloadStats":
+        """Fold a host copy of one chunk's prefix sample (at most
+        ``sample`` keys are read) drawn from ``n_rows`` rows; returns the
+        refreshed cumulative :class:`WorkloadStats`.  Host work only, so a
+        caller can read the sample off the device whenever it is ready."""
+        import numpy as np
+
+        self.n_rows += int(n_rows)
+        ks = np.asarray(host_keys).reshape(-1)[: self.sample]
         ks = ks[ks != np.uint32(0xFFFFFFFF)]
         self.sampled += int(ks.size)
         if ks.size:

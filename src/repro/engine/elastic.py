@@ -200,6 +200,7 @@ def export_executor(ex) -> tuple[dict, dict]:
     meta: dict = {"executor": _executor_label(ex)}
 
     if isinstance(ex, _ResolvingExecutor):
+        ex.settle_sample()  # the sketch a restore resumes from is complete
         sk_arrays, sk_meta = _export_sketch(ex._stats)
         _nest(arrays, "sketch", sk_arrays)
         meta["sketch"] = sk_meta

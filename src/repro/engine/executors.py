@@ -422,6 +422,15 @@ def resolve_plan(plan: GroupByPlan, keys: jnp.ndarray) -> GroupByPlan:
     return resolve_plan_stats(plan, stats)
 
 
+@functools.partial(jax.jit, static_argnames=("key_columns", "raw_keys", "rows"))
+def _head_keys(cols, *, key_columns, raw_keys, rows):
+    """The planner's sample as ONE compiled launch: the uint32 keys of a
+    chunk's first ``rows`` rows (``__mask__`` applied as EMPTY), through the
+    same ``chunk_key_column`` the operator stages the whole chunk with."""
+    head = Table({c: v[:rows] for c, v in cols.items()})
+    return chunk_key_column(head, key_columns, raw_keys)[0]
+
+
 class _ResolvingExecutor(_ExecutorBase):
     """Defers strategy/bound resolution to the first consumed chunk, then
     carries :class:`adaptive.RunningStats` across the stream and RE-PLANS
@@ -430,6 +439,16 @@ class _ResolvingExecutor(_ExecutorBase):
     operator — table, accumulators, grown bound — is adopted in place, so
     nothing replays).  Observed cardinality feeds capacity bounds through
     the operator's in-stream bound growth.
+
+    Only the first chunk's sample is read blocking (resolution needs it
+    before any dispatch).  Every later chunk's sample is one compiled
+    launch (before or behind the chunk's scan, see :meth:`_observe`), its
+    host copy started, and is folded into the sketch at the NEXT chunk:
+    re-plans see the stats up to the previous chunk, so an escalation comes
+    at most one chunk later, and stays exact since hybrid adopts the live
+    operator.  ``finalize`` leaves the last chunk's sample unfolded (no
+    re-plan follows it); :meth:`settle_sample` folds it, and a checkpoint
+    export calls that.
 
     The pre-resolution chunk is handed to the resolved executor through the
     same ``consume_async`` seam the stream uses, so ``auto`` inherits
@@ -443,6 +462,10 @@ class _ResolvingExecutor(_ExecutorBase):
         self._resolved = None
         self._stats = adaptive.RunningStats(domain=plan.execution.key_domain)
         self._escalated = False
+        self._pending = None  # the previous chunk's sample, not yet folded
+        self._planner = dict.fromkeys(
+            ("samples", "blocking_reads", "deferred_folds", "folds_waited"), 0
+        )
 
     @property
     def peak_buffered_chunks(self) -> int:
@@ -469,22 +492,68 @@ class _ResolvingExecutor(_ExecutorBase):
         return self._inner.event_counts() if self._inner else None
 
     def stats(self) -> dict:
-        return self._inner.stats() if self._inner else super().stats()
+        """The inner executor's stats plus a ``planner`` section: sample
+        launches, blocking reads, deferred folds, and the deferred folds
+        whose sample was not yet on the host (``folds_waited``)."""
+        out = self._inner.stats() if self._inner else super().stats()
+        out["planner"] = dict(self._planner)
+        return out
 
     def _sample_keys(self, chunk: Table) -> jnp.ndarray:
-        head = Table({k: v[: self.SAMPLE_ROWS] for k, v in chunk.columns.items()})
-        keys, _ = chunk_key_column(head, self._plan.keys, self._plan.raw_keys)
-        return keys
+        names = (*self._plan.keys, "__mask__")
+        # a host column sends only its head to the device
+        cols = {
+            c: v if isinstance(v, jax.Array) else np.asarray(v)[: self.SAMPLE_ROWS]
+            for c, v in chunk.columns.items() if c in names
+        }
+        self._planner["samples"] += 1
+        return _head_keys(
+            cols, key_columns=tuple(self._plan.keys), raw_keys=self._plan.raw_keys,
+            rows=min(self.SAMPLE_ROWS, chunk.num_rows),
+        )
 
-    def _observe(self, chunk: Table) -> None:
+    def _observe(self, chunk: Table) -> bool:
+        """Resolve on the first chunk's sample (read blocking), or fold the
+        previous chunk's.  Returns True when this chunk's sample is to be
+        launched behind its scan, by :meth:`_launch_sample` after dispatch.
+
+        A sample launched before its chunk's scan is ready once the previous
+        scan ends, so the next fold seldom waits; but then two scans can be
+        in flight at the next dispatch, each holding a copy of the scan's
+        carry (the scan donates none).  So the sample runs ahead only while
+        the carry is no larger than the chunk, which the ingest window holds
+        anyway.  Behind the scan, the next fold waits for that scan, and one
+        scan is in flight at each dispatch, as when every sample was read
+        blocking."""
         with obs_trace.span("plan_sample"):
-            stats = self._stats.update(self._sample_keys(chunk))
-            if self._inner is None:
-                self._resolved = resolve_plan_stats(self._plan, stats)
-                self._inner = make_executor(self._resolved)
-                self._inner.open()
-            else:
-                self._maybe_replan(stats)
+            if self._inner is not None:
+                self.settle_sample()
+                chunk_bytes = sum(int(v.nbytes) for v in chunk.columns.values())
+                if self._inner.device_table_bytes() > chunk_bytes:
+                    return True
+                self._launch_sample(chunk)
+                return False
+            keys = self._sample_keys(chunk)
+            self._planner["blocking_reads"] += 1
+            stats = self._stats.fold(jax.device_get(keys), keys.size)
+            self._resolved = resolve_plan_stats(self._plan, stats)
+            self._inner = make_executor(self._resolved)
+            self._inner.open()
+            return False
+
+    def _launch_sample(self, chunk: Table) -> None:
+        self._pending = self._sample_keys(chunk)
+        self._pending.copy_to_host_async()
+
+    def settle_sample(self) -> None:
+        """Fold the sample pending from the previous chunk into the sketch
+        and re-plan on the refreshed stats (a no-op with none pending)."""
+        keys, self._pending = self._pending, None
+        if keys is None:
+            return
+        self._planner["deferred_folds"] += 1
+        self._planner["folds_waited"] += not keys.is_ready()
+        self._maybe_replan(self._stats.fold(jax.device_get(keys), keys.size))
 
     def _maybe_replan(self, stats: adaptive.WorkloadStats) -> None:
         """hash→hybrid escalation on long streams: the first-chunk sample
@@ -512,12 +581,17 @@ class _ResolvingExecutor(_ExecutorBase):
         self._escalated = True
 
     def consume(self, chunk: Table) -> None:
-        self._observe(chunk)
+        later = self._observe(chunk)
         self._inner.consume(chunk)
+        if later:
+            self._launch_sample(chunk)
 
     def consume_async(self, chunk: Table):
-        self._observe(chunk)
-        return self._inner.consume_async(chunk)
+        later = self._observe(chunk)
+        token = self._inner.consume_async(chunk)
+        if later:
+            self._launch_sample(chunk)
+        return token
 
     def poll(self, token) -> None:
         # tokens stay valid across an escalation: hybrid adopts the SAME
