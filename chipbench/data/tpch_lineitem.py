@@ -112,17 +112,21 @@ def orderkey(order):
 
 
 def generate(seed: int, *, orders: int, lineitems: int | None, parts: int,
-             suppliers: int) -> dict:
-    """Every column of ``lineitem`` on the default device, from one jitted
-    call.  ``lineitems`` None keeps the base count of
-    :func:`base_lineitems`; a larger one (at most ``ceil(orders / 7)``
-    more) adds one line to that many one-line orders."""
+             suppliers: int, sharding=None) -> dict:
+    """Every column of ``lineitem`` from one jitted call: on the default
+    device, or laid out by ``sharding`` (rows split over a mesh, say, when
+    the row count divides by the mesh's size).  ``lineitems`` None keeps
+    the base count of :func:`base_lineitems`; a larger one (at most
+    ``ceil(orders / 7)`` more) adds one line to that many one-line
+    orders."""
     base = base_lineitems(orders)
     total = base if lineitems is None else lineitems
     if not base <= total <= base + -(-orders // 7):
         raise ValueError(f"{total} lineitems do not fit {orders} orders")
-    out = _generate(key_from_seed(seed), orders=orders, lineitems=total,
-                    extra=total - base, parts=parts, suppliers=suppliers)
+    gen = _generate if sharding is None else jax.jit(
+        _generate, static_argnames=_STATIC, out_shardings=sharding)
+    out = gen(key_from_seed(seed), orders=orders, lineitems=total,
+              extra=total - base, parts=parts, suppliers=suppliers)
     jax.block_until_ready(out)
     return out
 
@@ -138,9 +142,10 @@ def _text(key, strings, width: int, rows: int) -> jax.Array:
     return out
 
 
-@functools.partial(
-    jax.jit, static_argnames=("orders", "lineitems", "extra", "parts", "suppliers")
-)
+_STATIC = ("orders", "lineitems", "extra", "parts", "suppliers")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def _generate(key, *, orders, lineitems, extra, parts, suppliers):
     idx = jnp.arange(orders, dtype=jnp.int32)
     counts = 1 + idx % 7

@@ -5,8 +5,10 @@ one query of the cell; the window then drives the cell's traffic for
 ``--seconds``.  With ``--trace 0`` the result carries the cell's end-to-end
 metrics; with ``--trace 1`` the window runs under the JAX profiler and the
 program's ``obs.trace`` spans (nothing else is switched on) and the result
-carries the cell's per-layer metrics.  After the window closes and the
-device's peak memory is read, every query's result is compared with the
+carries the cell's per-layer metrics.  A cell's ``chips`` are the devices
+it gets, the devices whose peak memory it reports (the fullest) and the
+device planes of the trace it reads.  After the window closes and the
+peak memory is read, every query's result is compared with the
 configuration's float64 reference.
 """
 from __future__ import annotations
@@ -37,11 +39,13 @@ class Context:
     window_start: float
     window_end: float
     setup_s: float
-    memory_peak_bytes: int | None
+    memory_peak_bytes: int | None  # the fullest of the cell's chips
     rows_per_query: int
     bytes_per_query: int
-    peaks: dict
+    peaks: dict            # one chip's published peaks
+    chips: int             # the cell's chips, which share each query's work
     summary: object = None  # trace_reduce.Summary of a traced run
+    trace: object = None    # its trace_reduce.Trace, the cell's planes only
     spans: list | None = None  # obs.trace events inside the window
 
     @property
@@ -95,7 +99,10 @@ def metrics_for(bench: dict, workload: str, trace: bool) -> list:
     return [m for m in group if workload in m.get("workloads", [workload])]
 
 
-def check_chips(chips: int):
+def check_chips(chips: int) -> list:
+    """The cell's devices: the first ``chips`` TPUs JAX finds.  A
+    configuration laid out on several chips builds its mesh from the same
+    ``jax.devices()[:chips]``."""
     import jax
 
     devices = jax.devices()
@@ -103,7 +110,13 @@ def check_chips(chips: int):
         raise NoChip(f"no TPU found (JAX platform {devices[0].platform!r})")
     if len(devices) < chips:
         raise NoChip(f"the cell asks for {chips} chips, JAX found {len(devices)}")
-    return devices
+    return devices[:chips]
+
+
+def memory_peaks(devices) -> list:
+    """Each device's allocator ``peak_bytes_in_use``, None where the
+    backend reports none."""
+    return [(d.memory_stats() or {}).get("peak_bytes_in_use") for d in devices]
 
 
 def enable_compile_cache() -> str:
@@ -144,7 +157,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     traffic = load_traffic(w["traffic"])
     log(f"cell={workload} config={w['config']} traffic={w['traffic']} seed={seed} "
         f"seconds={seconds} trace={int(trace)} device={dev.device_kind} "
-        f"count={len(devices)} jax={jax.__version__} cache={cache}")
+        f"count={len(devices)} ids={[d.id for d in devices]} jax={jax.__version__} "
+        f"cache={cache}")
 
     data = config.make_data(cfg, seed)
     loadgen.warm(traffic, config, cfg, data)
@@ -166,21 +180,24 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
         if trace:
             jax.profiler.stop_trace()
             obs_trace.disable()
-    stats = dev.memory_stats() or {}
+    mem = memory_peaks(devices)
+    reported = [b for b in mem if b is not None]
     ctx = Context(
         queries=queries, window_start=t0, window_end=t1, setup_s=t0 - t_start,
-        memory_peak_bytes=stats.get("peak_bytes_in_use"),
+        memory_peak_bytes=max(reported) if reported else None,
         rows_per_query=cfg["lineitems"], bytes_per_query=config.bytes_per_query(cfg),
-        peaks=peak,
+        peaks=peak, chips=len(devices),
     )
     out_device = {"platform": dev.platform, "kind": dev.device_kind,
-                  "count": len(devices), "memory_peak_bytes": ctx.memory_peak_bytes}
+                  "count": len(devices), "memory_peak_bytes": ctx.memory_peak_bytes,
+                  "memory_peak_bytes_per_device": mem}
     breakdown = None
     if trace:
         ctx.spans = trace_reduce.spans_in(obs_trace.events(), t0 * 1e6, t1 * 1e6)
         obs_trace.clear()
-        ctx.summary = trace_reduce.summarize(
-            trace_reduce.load(trace_reduce.find_xplane(log_dir)))
+        ctx.trace = trace_reduce.cell_planes(
+            trace_reduce.load(trace_reduce.find_xplane(log_dir)), devices)
+        ctx.summary = trace_reduce.summarize(ctx.trace)
         out_device.update(busy_s=ctx.summary.busy_s, window_s=ctx.summary.window_s)
         breakdown = {"device_ops": ctx.summary.device_ops,
                      "idle_gaps": ctx.summary.idle_gaps}

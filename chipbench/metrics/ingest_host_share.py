@@ -1,5 +1,9 @@
-"""Self time of the ``consume_async`` spans (key combining, the planner's
-per-chunk sample, morselizing, dispatch) over the window, in percent."""
+"""Self time of the ``consume_async`` spans over the window, in percent:
+what a chunk's ingest leaves once its four child spans (``plan_sample``,
+``combine_keys``, ``morselize``, ``dispatch``) are taken out: the
+executors' calls between those stages and, where the planner's sample is
+launched behind the chunk's scan (a carry larger than the chunk), that
+launch."""
 from chipbench import trace_reduce as T
 
 

@@ -1,5 +1,5 @@
 """The device allocator's ``peak_bytes_in_use`` at the end of the window,
-in GiB."""
+on the fullest of the cell's chips, in GiB."""
 
 
 def read(ctx):

@@ -18,6 +18,20 @@ UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 BENCH = harness.load_benchmark()
 
 
+def check_chips_rule(bench):
+    """A cell takes 1 or 4 chips, as many as its configuration is laid out
+    on (its file's ``chips``, 1 where it states none), and at most half of
+    the cells, rounded down, or one, take 4."""
+    laid_out = {}
+    for c in bench["configs"]:
+        with open(os.path.join(ROOT, c["file"])) as f:
+            laid_out[c["name"]] = json.load(f).get("chips", 1)
+    for w in bench["workloads"]:
+        assert w["chips"] in (1, 4) and w["chips"] == laid_out[w["config"]], w
+    four = sum(w["chips"] == 4 for w in bench["workloads"])
+    assert four <= max(1, len(bench["workloads"]) // 2)
+
+
 def test_benchmark_json_shape():
     assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
                           "workloads", "end_to_end", "per_layer"}
@@ -34,8 +48,9 @@ def test_benchmark_json_shape():
         assert all(k in cfg for k in c["reduced"])
     for w in BENCH["workloads"]:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
-        assert NAME.match(w["name"]) and w["config"] in configs and w["chips"] == 1
+        assert NAME.match(w["name"]) and w["config"] in configs
         assert 1 <= len(w["why"]) <= 200
+    check_chips_rule(BENCH)
     metrics = BENCH["end_to_end"] + BENCH["per_layer"]
     assert len({m["name"] for m in metrics}) == len(metrics)
     for m in metrics:
@@ -52,6 +67,49 @@ def test_benchmark_json_shape():
     for w in cells:  # setup_s, another end-to-end metric, a per-layer one
         assert len(harness.metrics_for(BENCH, w, False)) >= 2
         assert harness.metrics_for(BENCH, w, True)
+
+
+ROWS4 = {"name": "tpch_q18agg_rows4", "source": "s", "reduced": ["scale_factor"],
+         "file": "chipbench/tests/configs/tpch_q18agg_rows4.json", "why": "w"}
+
+
+def _cell(name, config, chips):
+    return {"name": name, "config": config, "traffic": "solo", "chips": chips,
+            "why": "w"}
+
+
+FOUR = ("tpch_q18agg_rows4", 4)
+
+
+@pytest.mark.parametrize("cells, ok", [
+    pytest.param([FOUR], True, id="one-of-four"),
+    pytest.param([FOUR, FOUR], True, id="two-of-five"),
+    pytest.param([FOUR, FOUR, FOUR], True, id="three-of-six"),
+    pytest.param([FOUR, FOUR, FOUR, FOUR], False, id="four-of-seven"),
+    pytest.param([("tpch_q18agg_sf1", 4)], False, id="config-on-one-chip"),
+    pytest.param([("tpch_q18agg_rows4", 1)], False, id="config-on-four-chips"),
+    pytest.param([("tpch_q18agg_rows4", 2)], False, id="two-chips"),
+])
+def test_chips_rule(cells, ok):
+    """The rule on ``chips``, on copies of ``BENCHMARK.json`` built in
+    memory: each extra cell joins the three one-chip cells."""
+    extra = [_cell(f"extra{i}", *c) for i, c in enumerate(cells)]
+    bench = dict(BENCH, configs=BENCH["configs"] + [ROWS4],
+                 workloads=BENCH["workloads"] + extra)
+    if ok:
+        check_chips_rule(bench)
+    else:
+        with pytest.raises(AssertionError):
+            check_chips_rule(bench)
+
+
+def test_chips_rule_among_three_cells():
+    """A second four-chip cell among three is one too many."""
+    cells = [_cell("a", *FOUR), _cell("b", *FOUR), BENCH["workloads"][0]]
+    bench = dict(BENCH, configs=BENCH["configs"] + [ROWS4], workloads=cells)
+    with pytest.raises(AssertionError):
+        check_chips_rule(bench)
+    check_chips_rule(dict(bench, workloads=cells[1:]))
 
 
 @pytest.mark.parametrize("workload", [w["name"] for w in BENCH["workloads"]])

@@ -12,6 +12,8 @@ from chipbench import trace_reduce as T
 
 MS = 1e6  # nanoseconds
 IDLE_METRICS = ("planner_idle_share", "staging_idle_share", "dispatch_idle_share")
+BUSY = {"/device:TPU:0": [("fusion.1", 1 * MS, 3 * MS), ("while.2", 6 * MS, 7 * MS)]}
+REAL = {"cell_planes": T.cell_planes, "summarize": T.summarize}
 
 
 def _trace(devices=None):
@@ -80,6 +82,17 @@ def test_mean_over_device_planes():
     assert _read("dispatch_idle_share", t) == pytest.approx(7.5)
 
 
+def test_cell_planes_leave_out_an_idle_chip():
+    """A chip that is not the cell's, idle all through, changes no share
+    once the trace is kept to the cell's planes."""
+    two = _trace(devices=dict(BUSY, **{"/device:TPU:1": []}))
+    cell = T.cell_planes(two, [SimpleNamespace(id=0)])
+    for m in IDLE_METRICS + ("device_idle_share",):
+        assert _read(m, cell) == _read(m, _trace()), m
+    assert _read("device_idle_share", two) == pytest.approx(85.0)
+    assert _read("planner_idle_share", two) == pytest.approx(7.5)
+
+
 @pytest.mark.parametrize("metric", IDLE_METRICS)
 def test_nothing_to_read_without_a_device_plane(metric):
     assert _read(metric, _trace(devices={})) is None
@@ -131,3 +144,21 @@ def test_traced_line_carries_planner_sample_share(workload, cpu_run):
     metrics = cpu_run(workload, trace=True)["metrics"]
     assert 0 < metrics["planner_sample_share"]["value"] < 100
     assert metrics["planner_sample_share"]["unit"] == "%"
+
+
+@pytest.mark.parametrize("workload", [w["name"] for w in harness.load_benchmark()["workloads"]])
+def test_traced_line_carries_idle_shares(workload, monkeypatch, cpu_run):
+    """Through ``harness.run``: the profiler's trace stood in for by the
+    synthetic one with a second, idle chip, which the harness leaves out
+    (the cell has one chip); each idle share reads in the result line."""
+    for name, fn in REAL.items():
+        monkeypatch.setattr(T, name, fn)
+    monkeypatch.setattr(T, "load", lambda path: _trace(
+        devices=dict(BUSY, **{"/device:TPU:1": []})))
+    r = cpu_run(workload, trace=True)
+    metrics = {m: v["value"] for m, v in r["metrics"].items()}
+    assert metrics["planner_idle_share"] == pytest.approx(5.0)
+    assert metrics["staging_idle_share"] == pytest.approx(10.0)
+    assert metrics["dispatch_idle_share"] == pytest.approx(15.0)
+    assert metrics["device_idle_share"] == pytest.approx(70.0)
+    assert r["device"]["busy_s"] == pytest.approx(0.003)

@@ -1,5 +1,7 @@
 """The trace reduction: busy union, idle share, top ops, gap labels, and
 span self times."""
+from types import SimpleNamespace
+
 import pytest
 
 from chipbench import trace_reduce as T
@@ -44,6 +46,34 @@ def test_window_annotation_required():
     t.host[0] = t.host[0][1:]
     with pytest.raises(ValueError, match="chipbench.window"):
         T.summarize(t)
+
+
+def _with_plane(trace, name, ops):
+    return T.Trace(devices=dict(trace.devices, **{name: ops}), host=trace.host)
+
+
+def test_cell_planes_leave_out_another_chip():
+    """A busy plane and an idle extra one: kept to the cell's one device,
+    busy time, top ops and idle gaps read as on that plane alone."""
+    one = _trace()
+    two = _with_plane(one, "/device:TPU:1", [])
+    cell = T.cell_planes(two, [SimpleNamespace(id=0)])
+    assert list(cell.devices) == ["/device:TPU:0"] and cell.host is one.host
+    assert T.summarize(cell) == T.summarize(one)
+    assert T.summarize(two).busy_s == pytest.approx(T.summarize(one).busy_s / 2)
+    assert list(T.cell_planes(two, [SimpleNamespace(id=1)]).devices) == ["/device:TPU:1"]
+    with pytest.raises(ValueError, match="TPU:2"):
+        T.cell_planes(two, [SimpleNamespace(id=0), SimpleNamespace(id=2)])
+
+
+def test_every_number_a_mean_per_plane():
+    """Two chips that ran alike read as one: top ops too, per chip."""
+    one = _trace()
+    two = _with_plane(one, "/device:TPU:1", one.devices["/device:TPU:0"])
+    a, b = T.summarize(one), T.summarize(two)
+    assert b.busy_s == pytest.approx(a.busy_s) and b.window_s == a.window_s
+    assert b.device_ops == [[n, pytest.approx(t)] for n, t in a.device_ops]
+    assert b.idle_gaps == [[n, pytest.approx(t)] for n, t in a.idle_gaps]
 
 
 def _ev(name, ts, dur, tid=1):

@@ -6,7 +6,9 @@ Device side: the JAX profiler's ``.xplane.pb``.  Every TPU device plane's
 over the traced window is the device's busy time, and the gaps between
 them are idle.  Each idle gap is labelled with the innermost host event
 (the benchmark's own ``TraceAnnotation`` or JAX's dispatch and transfer
-events) that covers its midpoint on the profiler's clock.
+events) that covers its midpoint on the profiler's clock.  The harness
+keeps the planes of the cell's own devices (:func:`cell_planes`), and every
+number is a mean over those planes, so a cell on four chips reads per chip.
 
 Host side: the ``obs.trace`` spans (Chrome trace events on
 ``perf_counter``, in microseconds).  A span's self time is its duration
@@ -42,7 +44,9 @@ class Trace:
 
 @dataclass
 class Summary:
-    busy_s: float        # union of op intervals in the window, mean over devices
+    """Each number a mean over the trace's device planes."""
+
+    busy_s: float        # union of op intervals in the window
     window_s: float
     device_ops: list     # [[name, seconds], ...], most time first
     idle_gaps: list      # [[host label, seconds], ...], most idle time first
@@ -78,6 +82,20 @@ def load(path: str) -> Trace:
                 trace.host.append([(e.name, e.start_ns, e.start_ns + e.duration_ns)
                                    for e in line.events])
     return trace
+
+
+def cell_planes(trace: Trace, devices) -> Trace:
+    """``trace`` with only the device planes of ``devices``.  The plane
+    ``/device:TPU:<n>`` is the device whose ``jax.Device.id`` is n: on a
+    four-chip v5e host, programs launched on devices 0 to 3 (the chips at
+    coords (0,0), (1,0), (0,1), (1,1)) run on planes 0 to 3.  A device with
+    no plane in the trace is an error."""
+    names = [f"/device:TPU:{d.id}" for d in devices]
+    missing = [n for n in names if n not in trace.devices]
+    if missing:
+        raise ValueError(f"the trace holds no plane {missing}; it has "
+                         f"{sorted(trace.devices)}")
+    return Trace(devices={n: trace.devices[n] for n in names}, host=trace.host)
 
 
 def op_name(hlo: str, start: float, modules: list) -> str:
@@ -161,7 +179,7 @@ def summarize(trace: Trace, top: int = 10) -> Summary:
     return Summary(
         busy_s=sum(busy) / len(busy) * ns,
         window_s=(hi - lo) * ns,
-        device_ops=[[n, t * ns] for n, t in
+        device_ops=[[n, t * ns / len(trace.devices)] for n, t in
                     sorted(op_time.items(), key=lambda kv: -kv[1])[:top]],
         idle_gaps=[[n, t * ns / len(trace.devices)] for n, t in
                    sorted(gap_time.items(), key=lambda kv: -kv[1])[:top]],
